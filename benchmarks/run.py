@@ -65,6 +65,8 @@ def _dump(short: str, rows, summary, meta: dict) -> None:
 
 def main() -> None:
     import repro.obs as obs
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_analysis, bench_batchsize, bench_interleave,
                             bench_program, bench_query, bench_serve,
                             bench_shard, bench_stream, bench_tier,

@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.tuner import choose_serve_plan
 from repro.data import rmat_edges, update_stream
 from repro.serve import (Analytics, DegreeRead, ManualClock, PointRead,
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--qps", type=float, default=2000.0,
                     help="virtual arrival rate the serve plan is keyed on")
     args = ap.parse_args()
+    compile_cache.enable()
 
     src, dst = rmat_edges(args.vertices, args.edges, seed=0)
     service = GraphService.from_coo(

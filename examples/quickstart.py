@@ -8,10 +8,13 @@ query edges, and let the adaptation layer pick an execution plan.
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (batch_update, build_from_coo, choose_plan,
                         gtchain_contiguity, read_edges, INSERT, DELETE)
 from repro.data import rmat_edges
 from repro.graph import bfs, pagerank
+
+compile_cache.enable()
 
 # --- LoadGraph -------------------------------------------------------------
 NV = 1000

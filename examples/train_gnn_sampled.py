@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import batch_update, build_from_coo
 from repro.data import rmat_edges
 from repro.graph import sample_subgraph
@@ -22,6 +23,7 @@ from repro.optim import AdamWConfig, adamw_update, init_opt_state
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--vertices", type=int, default=512)
     ap.add_argument("--edges", type=int, default=8192)

@@ -1,223 +1,71 @@
-"""Version-adaptive JAX / Pallas compatibility layer.
+"""The Pallas-TPU and mesh spellings the kernels and launch code share.
 
-The Pallas TPU surface and the mesh-context API have drifted across JAX
-releases; this module is the single place that knows about the drift, so
-kernels and launch code are written against one stable spelling:
+Written against the installed JAX (0.9): one place names the Mosaic
+options class, the scalar-prefetch grid spec, VMEM scratch shapes, the
+ambient-mesh context and ``shard_map``, so kernels and launch code use one
+spelling.
 
-  * ``tpu_compiler_params(dimension_semantics=...)`` — newer JAX spells the
-    Mosaic options class ``pltpu.CompilerParams``; 0.4.x spells it
-    ``pltpu.TPUCompilerParams``; ancient Pallas took a raw
-    ``{"mosaic": {...}}`` dict.  All three accept ``dimension_semantics``
-    (where supported — unknown fields are dropped, they are scheduling
-    hints, not semantics).
-  * ``prefetch_grid_spec(...)`` — ``pltpu.PrefetchScalarGridSpec``, the
-    scalar-prefetch pipeline used for all pointer-chasing kernels.
-  * ``resolve_interpret(impl)`` — maps the repo-wide ``impl=`` convention
-    ("xla" | "pallas" | "pallas_interpret") to ``pallas_call``'s
-    ``interpret=``: explicit interpret always interprets, and ``"pallas"``
-    transparently falls back to interpret mode off-TPU so the kernel path
-    stays exercised on CPU CI.
-  * ``set_mesh(mesh)`` — context manager covering ``jax.set_mesh`` (new),
-    ``jax.sharding.use_mesh`` (mid), and the plain ``Mesh`` context manager
-    (0.4.x) so bare-``PartitionSpec`` constraints and shard_map resolve.
-  * ``shard_map(f, ...)`` — the new ``jax.shard_map(f, in_specs, out_specs,
-    axis_names=...)`` signature, emulated on 0.4.x via
-    ``jax.experimental.shard_map.shard_map`` with ``auto=`` for the
-    unmentioned mesh axes and the mesh taken from the ambient context.
+``resolve_interpret(impl)`` maps the repo-wide ``impl=`` convention
+("xla" | "pallas" | "pallas_interpret") to ``pallas_call``'s
+``interpret=``: ``"pallas"`` always means the compiled Mosaic kernel (it
+fails to lower where there is no TPU) and only ``"pallas_interpret"``
+runs the kernel body in the interpreter.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
 from typing import Any, Optional, Sequence
 
 import jax
-
-try:  # Pallas is optional: CPU-only wheels may ship without the TPU backend
-    from jax.experimental import pallas as pl  # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover - exercised only on pallas-less installs
-    pl = None
-    pltpu = None
-    HAS_PALLAS = False
-
-
-# --------------------------------------------------------------------------
-# Pallas compiler params / grid specs
-# --------------------------------------------------------------------------
-
-def _compiler_params_cls():
-    """The Mosaic params class under whichever name this JAX exports it."""
-    if pltpu is None:
-        return None
-    return (getattr(pltpu, "CompilerParams", None)
-            or getattr(pltpu, "TPUCompilerParams", None))
+from jax.experimental.pallas import tpu as pltpu
 
 
 def tpu_compiler_params(*, dimension_semantics: Optional[Sequence[str]] = None,
                         **kwargs: Any):
-    """Build ``compiler_params`` for ``pl.pallas_call`` on any JAX version.
-
-    Unknown fields are dropped rather than raised: every supported field
-    (``dimension_semantics``, ``vmem_limit_bytes``, ...) is a compiler hint
-    whose absence changes scheduling, never results.
-    """
+    """``compiler_params`` for ``pl.pallas_call`` (``pltpu.CompilerParams``)."""
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    cls = _compiler_params_cls()
-    if cls is None:
-        return {"mosaic": dict(kwargs)}
-    try:
-        accepted = set(inspect.signature(cls).parameters)
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        accepted = None
-    if accepted is not None:
-        kwargs = {k: v for k, v in kwargs.items() if k in accepted}
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def prefetch_grid_spec(**kwargs: Any):
-    """``pltpu.PrefetchScalarGridSpec`` under whichever module exports it."""
-    if pltpu is not None and hasattr(pltpu, "PrefetchScalarGridSpec"):
-        return pltpu.PrefetchScalarGridSpec(**kwargs)
-    if pl is not None and hasattr(pl, "PrefetchScalarGridSpec"):
-        return pl.PrefetchScalarGridSpec(**kwargs)
-    raise NotImplementedError(
-        "PrefetchScalarGridSpec unavailable: this JAX build has no Pallas "
-        "TPU support; use the impl='xla' oracle path instead.")
+    """``pltpu.PrefetchScalarGridSpec``: the scalar-prefetch pipeline used by
+    every pointer-chasing kernel."""
+    return pltpu.PrefetchScalarGridSpec(**kwargs)
 
 
 def vmem(shape: Sequence[int], dtype) -> Any:
     """A VMEM scratch-shape spec (``pltpu.VMEM``) for ``pallas_call``."""
-    if pltpu is not None and hasattr(pltpu, "VMEM"):
-        return pltpu.VMEM(tuple(shape), dtype)
-    raise NotImplementedError(
-        "VMEM scratch unavailable: this JAX build has no Pallas TPU support")
-
-
-def interpret_default() -> bool:
-    """True when Pallas must run in interpret mode (no TPU backend)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - backend probing failed
-        return True
+    return pltpu.VMEM(tuple(shape), dtype)
 
 
 def resolve_interpret(impl: str) -> bool:
     """Map the repo ``impl=`` convention to ``pallas_call(interpret=...)``.
 
-    ``"pallas_interpret"`` always interprets; ``"pallas"`` compiles on TPU
-    and falls back to interpret mode on CPU/GPU CI so the kernel path is
-    still the one exercised.
+    ``"pallas"`` compiles the Mosaic kernel, on every backend: off-TPU it
+    fails to lower instead of quietly switching to the interpreter.
+    ``"pallas_interpret"`` always interprets.
     """
     if impl == "pallas_interpret":
         return True
     if impl == "pallas":
-        return interpret_default()
+        return False
     raise ValueError(f"not a pallas impl: {impl!r}")
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any JAX version.
-
-    0.4.x returns a one-element list of dicts (one per partition); newer
-    JAX returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-# --------------------------------------------------------------------------
-# Mesh context + shard_map
-# --------------------------------------------------------------------------
-
-def current_mesh():
-    """The ambient mesh (set by :func:`set_mesh` / ``with mesh:``), or None.
-
-    Only consulted on 0.4.x, where the ``Mesh`` context manager records
-    itself in ``thread_resources`` — newer JAX resolves the mesh itself.
-    """
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover - internal layout changed
-        pass
-    return None
-
-
-@contextlib.contextmanager
 def set_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh on any JAX version.
-
-    Always enters the ``Mesh`` context (so 0.4.x shard_map /
-    with_sharding_constraint resolve bare PartitionSpecs) and additionally
-    ``jax.set_mesh`` / ``jax.sharding.use_mesh`` where they exist.
-    """
-    with contextlib.ExitStack() as es:
-        es.enter_context(mesh)
-        if hasattr(jax, "set_mesh"):
-            es.enter_context(jax.set_mesh(mesh))
-        elif hasattr(jax.sharding, "use_mesh"):
-            es.enter_context(jax.sharding.use_mesh(mesh))
-        yield mesh
+    """Enter ``mesh`` as the ambient mesh (``jax.set_mesh``), so bare
+    ``PartitionSpec`` constraints and ``shard_map`` resolve against it."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs, axis_names=None,
-              check_rep: Optional[bool] = None):
-    """``jax.shard_map``'s new signature on every JAX version.
-
-    ``axis_names`` lists the mesh axes mapped manually; unmentioned axes
-    stay automatic (GSPMD).  On 0.4.x this lowers to
-    ``jax.experimental.shard_map.shard_map(..., auto=<unmentioned axes>)``
-    with the mesh taken from ``mesh=`` or the ambient context.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            accepted = set(inspect.signature(jax.shard_map).parameters)
-        except (TypeError, ValueError):  # pragma: no cover
-            accepted = None
-        kw = {"in_specs": in_specs, "out_specs": out_specs}
-        if mesh is not None:
-            kw["mesh"] = mesh
-        if axis_names is not None:
-            # axis_names changes which axes are manual — never droppable
-            if accepted is not None and "axis_names" not in accepted:
-                raise NotImplementedError(
-                    "this jax.shard_map has no axis_names parameter; "
-                    "compat.shard_map cannot express partial-manual axes")
-            kw["axis_names"] = axis_names
-        if check_rep is not None and accepted is not None:
-            # renamed check_rep -> check_vma in newer JAX; same meaning
-            if "check_rep" in accepted:
-                kw["check_rep"] = check_rep
-            elif "check_vma" in accepted:
-                kw["check_vma"] = check_rep
-        elif check_rep is not None:
-            kw["check_rep"] = check_rep
-        return jax.shard_map(f, **kw)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-    m = mesh if mesh is not None else current_mesh()
-    if m is None:
-        raise ValueError(
-            "compat.shard_map needs a mesh: pass mesh= or enter "
-            "compat.set_mesh(mesh) first")
-    manual = (frozenset(axis_names) if axis_names is not None
-              else frozenset(m.axis_names))
-    partial_manual = bool(frozenset(m.axis_names) - manual)
-    kw = {"mesh": m, "in_specs": in_specs, "out_specs": out_specs}
-    if partial_manual:
-        # 0.4.x partial-manual lowering (auto=) trips SPMD-partitioner
-        # Check failures; running every axis manual is equivalent here —
-        # the specs already say "replicated" for unmentioned axes and the
-        # body never names them — but the rep checker can't always prove
-        # it, so it is disabled for this case.
-        kw["check_rep"] = False
-    if check_rep is not None:
-        kw["check_rep"] = check_rep
-    return _shard_map(f, **kw)
+              check_vma: bool = True):
+    """``jax.shard_map``; ``axis_names`` lists the mesh axes mapped
+    manually (the unmentioned ones stay automatic), all of them when None."""
+    kw = {"in_specs": in_specs, "out_specs": out_specs,
+          "check_vma": check_vma}
+    if mesh is not None:
+        kw["mesh"] = mesh
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)

@@ -21,7 +21,7 @@ from repro.core.traversal import (Partition, PlacementPlan, gtchain_partition,
                                   scan_vertices_cond, vertex_table_partition,
                                   read_vertex)
 from repro.core.tuner import (ExecPlan, SystemProbe, choose_engine_impl,
-                              choose_plan)
+                              choose_plan, system_probe)
 from repro.core.csr import (CSRGraph, csr_build, csr_build_counted,
                             csr_degrees, csr_empty, csr_in_degrees,
                             csr_pagerank_sweep, csr_pull, csr_push,

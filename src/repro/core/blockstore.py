@@ -163,14 +163,15 @@ def gtchain_contiguity(store: BlockStore) -> jax.Array:
 
     This is the tuner's ``P_h`` statistic — the probability that the
     "hardware prefetch" analogue (sequential streaming of the block array)
-    covers the next block of the chain.  1.0 right after build/compact.
+    covers the next block of the chain.  1.0 right after build/compact,
+    and for a store with fewer than two live blocks (no pair to miss).
     """
     order = gtchain_order(store)
     live = store.owner[order] != NULL
     adj = (order[1:] - order[:-1]) == 1
     pair_live = live[1:] & live[:-1]
-    n = jnp.maximum(pair_live.sum(), 1)
-    return (adj & pair_live).sum() / n
+    n = pair_live.sum()
+    return jnp.where(n > 0, (adj & pair_live).sum() / jnp.maximum(n, 1), 1.0)
 
 
 def sort_blocks(store: BlockStore, block_ids: jax.Array) -> BlockStore:

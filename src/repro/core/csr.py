@@ -195,7 +195,8 @@ def csr_query(g: CSRGraph, qs: jax.Array, qd: jax.Array
 # ---------------------------------------------------------------------------
 
 def _segment_reduce(msg, seg, nv: int, combine: str, impl: str):
-    from repro.core.engine import SEMIRINGS, _segment_sum
+    from repro.core.engine import SEMIRINGS, _check_combine, _segment_sum
+    _check_combine(combine, impl)
     if combine == "sum":
         return _segment_sum(msg, seg, nv, impl)
     return SEMIRINGS[combine].segment_reduce(msg, seg, num_segments=nv)
@@ -221,8 +222,7 @@ def csr_push(g: CSRGraph, x: jax.Array,
         return jnp.full((nv,), sr.fill, x.dtype)
     ok = g.row != nv
     row_safe = jnp.where(ok, g.row, 0)
-    gather_impl = impl if combine == "sum" else "xla"
-    xs = _gather_values(x, row_safe, gather_impl)
+    xs = _gather_values(x, row_safe, impl)
     if active is not None:
         ok = ok & active[row_safe]
     msg = jnp.where(ok, dense_f(xs, g.weights), sr.fill)
@@ -245,8 +245,7 @@ def csr_pull(g: CSRGraph, x: jax.Array,
         return jnp.full((nv,), sr.fill, x.dtype)
     ok = g.row != nv
     dst_safe = jnp.clip(g.indices, 0, nv - 1)
-    gather_impl = impl if combine == "sum" else "xla"
-    xd = _gather_values(x, dst_safe, gather_impl)
+    xd = _gather_values(x, dst_safe, impl)
     if active_dst is not None:
         ok = ok & active_dst[dst_safe]
     msg = jnp.where(ok, dense_f(xd, g.weights), sr.fill)

@@ -23,12 +23,12 @@ Every sweep takes ``impl=``:
   * ``"pallas"``         — the co-designed path: the data-dependent gathers
     run through the scalar-prefetched ``block_gather`` kernel and the
     destination scatter through the GTChain ``segment_matmul`` kernel
-    (the paper's coroutine-interleave mode; interpret-mode fallback
-    off-TPU via :mod:`repro.compat`),
-  * ``"pallas_interpret"`` — kernel bodies interpreted everywhere (CI).
+    (the paper's coroutine-interleave mode), compiled by Mosaic — it
+    needs a TPU and never falls back to the interpreter,
+  * ``"pallas_interpret"`` — the same kernels, interpreted (CPU tests).
 
-``min``/``max`` combines always use the oracle (the MXU accumulation
-kernel is additive); the tuner never routes frontier tasks to Pallas.
+The MXU accumulation kernel is additive, so a Pallas impl is refused for
+``min``/``max`` combines; the tuner never routes frontier tasks to Pallas.
 Pick ``impl`` per graph/backend with :func:`repro.core.tuner.choose_plan`.
 """
 from __future__ import annotations
@@ -43,11 +43,7 @@ import jax.numpy as jnp
 from repro.core.blockstore import NULL, PAD
 from repro.core.cblist import CBList
 from repro.core.traversal import lane_mask
-
-try:
-    from repro.kernels import gather_rows, segment_matmul
-except Exception:   # Pallas-less JAX build: the XLA oracle stays importable
-    gather_rows = segment_matmul = None
+from repro.kernels import gather_rows, segment_matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,16 +87,8 @@ def _gather_values(x: jax.Array, ids: jax.Array, impl: str) -> jax.Array:
     """
     if impl == "xla":
         return x[ids]
-    if gather_rows is None:
-        raise NotImplementedError(
-            f"impl={impl!r} needs Pallas, which this JAX build lacks "
-            "(repro.compat.HAS_PALLAS is False); use impl='xla'")
-    flat = ids.reshape(-1)
-    if x.ndim == 1:
-        out = gather_rows(x[:, None], flat, rows_per_step=1, impl=impl)[:, 0]
-        return out.reshape(ids.shape)
-    out = gather_rows(x, flat, rows_per_step=1, impl=impl)
-    return out.reshape(ids.shape + (x.shape[1],))
+    out = gather_rows(x, ids.reshape(-1), impl=impl)
+    return out.reshape(ids.shape + x.shape[1:])
 
 
 def _segment_sum(msg: jax.Array, seg: jax.Array, num_segments: int,
@@ -108,13 +96,14 @@ def _segment_sum(msg: jax.Array, seg: jax.Array, num_segments: int,
     """Flat segment-sum via the GTChain segment_matmul kernel or the oracle."""
     if impl == "xla":
         return jax.ops.segment_sum(msg, seg, num_segments=num_segments)
-    if segment_matmul is None:
-        raise NotImplementedError(
-            f"impl={impl!r} needs Pallas, which this JAX build lacks "
-            "(repro.compat.HAS_PALLAS is False); use impl='xla'")
-    data = msg[:, None] if msg.ndim == 1 else msg
-    out = segment_matmul(data, seg, num_segments, impl=impl)
-    return out[:, 0] if msg.ndim == 1 else out
+    return segment_matmul(msg, seg, num_segments, impl=impl)
+
+
+def _check_combine(combine: str, impl: str) -> None:
+    if impl != "xla" and combine != "sum":
+        raise ValueError(
+            f"impl={impl!r} accumulates on the MXU, which only sums; a "
+            f"{combine!r} combine needs impl='xla'")
 
 
 def process_vertex(cbl: CBList, f: Callable, x: jax.Array,
@@ -160,11 +149,11 @@ def _process_edge_push(cbl: CBList, x: jax.Array,
                        *, dense_f: Callable = _DEFAULT_EDGE_F,
                        combine: str = "sum",
                        impl: str = "xla") -> jax.Array:
+    _check_combine(combine, impl)
     st = cbl.store
     nv = cbl.capacity_vertices
     owner_safe = jnp.maximum(st.owner, 0)
-    gather_impl = impl if combine == "sum" else "xla"
-    xs = _gather_values(x, owner_safe, gather_impl)      # [NB] per-block src value
+    xs = _gather_values(x, owner_safe, impl)             # [NB] per-block src value
     mask = lane_mask(st)
     if active is not None:
         mask = mask & active[owner_safe][:, None]
@@ -209,12 +198,12 @@ def _process_edge_pull(cbl: CBList, x: jax.Array,
                        *, dense_f: Callable = _DEFAULT_EDGE_F,
                        combine: str = "sum",
                        impl: str = "xla") -> jax.Array:
+    _check_combine(combine, impl)
     st = cbl.store
     nv = cbl.capacity_vertices
     mask = lane_mask(st)
     dst_safe = jnp.clip(st.keys, 0, nv - 1)
-    gather_impl = impl if combine == "sum" else "xla"
-    xd = _gather_values(x, dst_safe, gather_impl)        # [NB, B] random gather
+    xd = _gather_values(x, dst_safe, impl)               # [NB, B] random gather
     if active_dst is not None:
         mask = mask & active_dst[dst_safe]
     msg = dense_f(xd, st.vals)

@@ -49,18 +49,55 @@ MIN_PALLAS_LANES = 4096
 
 @dataclasses.dataclass(frozen=True)
 class SystemProbe:
-    """System configuration probe results (prefabricated constants for the
-    dry-run container; a real TPU deployment would microbenchmark these)."""
-    hbm_bw_gbps: float = 819.0          # v5e HBM bandwidth
+    """System configuration probe: the device facts and cost constants the
+    tuner plans with.  Look one up by device with :func:`system_probe`."""
+    hbm_bw_gbps: float                  # HBM bandwidth
+    vmem_bytes: int                     # VMEM per core -> lookahead cap
+    # the paper's C_m / C_coro rule in TPU terms: modelling constants of
+    # this repo, not measurements (a deployment that measures them passes
+    # its own probe)
     block_fetch_overhead_us: float = 0.5   # exposed latency of a cold block DMA
     scalar_prefetch_overhead_us: float = 0.05  # per-block SMEM/index setup
     remote_message_overhead_us: float = 2.0  # per-block cross-shard collective cost
-    vmem_bytes: int = 64 * 2 ** 20      # ~64 MiB usable VMEM on v5e half?  -> lookahead cap
     max_lookahead: int = 8
+    # True only where a chip run measured the compiled Pallas sweep faster
+    # than the XLA oracle on this device kind.  Until then the tuner keeps
+    # every sweep on XLA: on v5e at Graph500 scale 22 one PageRank sweep
+    # took 21.371 s compiled against 2.096 s on XLA (chip_smoke.py).
+    pallas_sweep_faster: bool = False
     replica_read_lanes_per_s: float = 250_000.0  # read lanes/s one snapshot
                                                  # replica absorbs (sizes the
                                                  # read plane from measured
                                                  # pressure)
+
+
+_V5E = SystemProbe(
+    hbm_bw_gbps=819.0,            # Google Cloud documentation, "TPU v5e"
+    vmem_bytes=128 * 2 ** 20)     # libtpu's v5e VMEM capacity (the size its
+                                  # compiler reports on a VMEM overflow)
+
+# keyed by ``device_kind`` for TPUs and by platform otherwise.  Host runs
+# (tests, CI) never run a Pallas sweep — off-TPU every impl resolves to the
+# XLA oracle — so they plan with the numbers of the chip this repo targets.
+SYSTEM_PROBES = {
+    "TPU v5 lite": _V5E,
+    "cpu": _V5E,
+}
+
+
+def system_probe(device=None) -> SystemProbe:
+    """The :class:`SystemProbe` of ``device`` (default: the first device).
+    A device missing from :data:`SYSTEM_PROBES` is an error, not a default."""
+    device = device or jax.devices()[0]
+    key = (device.device_kind if device.platform == "tpu"
+           else device.platform)
+    try:
+        return SYSTEM_PROBES[key]
+    except KeyError:
+        raise ValueError(
+            f"no SystemProbe for device {key!r} (known: "
+            f"{sorted(SYSTEM_PROBES)}); add its entry to "
+            "repro.core.tuner.SYSTEM_PROBES") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,8 +230,12 @@ def choose_plan(cbl, task, probe: Optional[SystemProbe] = None,
     adaptation starts from (tiered only).  With ``signals=None`` the plan
     is bit-identical to the static decision.
     """
+    # the MXU accumulation kernels only sum: a program with a min/max sweep
+    # stays on the oracle (the engine refuses Pallas for those combines)
+    sums_only = all(sw.combine == "sum" for sw in getattr(task, "sweeps", ()))
+    prog = task
     task = getattr(task, "task", task)       # VertexProgram -> its metadata
-    probe = probe or SystemProbe()
+    probe = probe or system_probe()
     if on_tpu is None:
         on_tpu = jax.default_backend() == "tpu"
     from repro.core.tiered import TieredGraph
@@ -204,9 +245,10 @@ def choose_plan(cbl, task, probe: Optional[SystemProbe] = None,
         # whose only knob is whether its lane extent amortizes the Pallas
         # stream setup.  The sealed fraction is reported so bench output can
         # correlate plan choices with tier occupancy.
-        plan = choose_plan(cbl.delta, task, probe, on_tpu=on_tpu,
+        plan = choose_plan(cbl.delta, prog, probe, on_tpu=on_tpu,
                            signals=signals)
-        run_impl = ("pallas" if on_tpu and task == "scan_all"
+        run_impl = ("pallas" if on_tpu and probe.pallas_sweep_faster
+                    and task == "scan_all" and sums_only
                     and cbl.run_capacity >= MIN_PALLAS_LANES else "xla")
         plan = dataclasses.replace(
             plan, run_impl=run_impl,
@@ -221,7 +263,8 @@ def choose_plan(cbl, task, probe: Optional[SystemProbe] = None,
                      run_capacity=int(cbl.run_capacity),
                      seal_after_epochs=plan.seal_after_epochs,
                      rule=("run lanes >= pallas floor" if run_impl == "pallas"
-                           else "run lanes below pallas floor or off-TPU"))
+                           else "run lanes below pallas floor, off-TPU or "
+                                "Pallas not measured faster"))
         return plan
     if isinstance(cbl, CBList):
         n_shards = 1
@@ -275,8 +318,10 @@ def choose_plan(cbl, task, probe: Optional[SystemProbe] = None,
     # engine impl: the scalar-prefetched kernels only pay when (a) a real
     # TPU pipeline exists, (b) the sweep is dense enough to amortize the
     # stream setup, (c) the strategy calls for software prefetch at all
-    # (All-Hard == contiguous oracle ops by definition).
-    impl = ("pallas" if on_tpu and strategy != "all_hard"
+    # (All-Hard == contiguous oracle ops by definition), and (d) the probe
+    # says the compiled kernel was measured faster on this device.
+    impl = ("pallas" if on_tpu and probe.pallas_sweep_faster
+            and strategy != "all_hard" and sums_only
             and partition == "gtchain" and lanes >= MIN_PALLAS_LANES
             else "xla")
     route_lane_cap, route_rounds = 0, 1
@@ -421,7 +466,7 @@ def choose_serve_plan(arrival_qps: float, mean_lanes_per_request: float = 8.0,
                 "n": sig_qps.n}
         sig_lanes = signals.get("read_lanes_per_s")
         if sig_lanes is not None and sig_lanes.n >= MIN_SIGNAL_SAMPLES:
-            probe = probe or SystemProbe()
+            probe = probe or system_probe()
             cap = (probe.replica_read_lanes_per_s
                    * SERVE_REPLICA_TARGET_UTIL)
             if max_replicas is None:

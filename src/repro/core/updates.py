@@ -254,7 +254,8 @@ def _apply_inserts(cbl: CBList, src, dst, w, mask):
 
 def batch_update_stats(cbl, src: jax.Array, dst: jax.Array,
                        w: Optional[jax.Array] = None,
-                       op: Optional[jax.Array] = None):
+                       op: Optional[jax.Array] = None,
+                       grow_factor: int = 2):
     """:func:`batch_update` + per-batch :class:`UpdateStats` accounting.
 
     ``stats.dropped_edges > 0`` means the free stack ran out mid-batch;
@@ -264,14 +265,18 @@ def batch_update_stats(cbl, src: jax.Array, dst: jax.Array,
 
     A ShardedCBList routes each record to its source's owning shard via the
     owner-compacted fused path (one pipeline, obs on or off — per-shard
-    spans are attributed from the fused measurement).
+    spans are attributed from the fused measurement).  Where one shard's
+    free blocks cannot hold its share, every shard first grows by
+    ``grow_factor`` (the returned store is then larger), so a sharded store
+    drops only what the unsharded one would.
     """
     if not isinstance(cbl, CBList):
         from repro.core.tiered import TieredGraph, tiered_batch_update_stats
         if isinstance(cbl, TieredGraph):
             return tiered_batch_update_stats(cbl, src, dst, w, op)
         from repro.distributed.graph import sharded_batch_update_stats
-        return sharded_batch_update_stats(cbl, src, dst, w, op)
+        return sharded_batch_update_stats(cbl, src, dst, w, op,
+                                          grow_factor=grow_factor)
     return _batch_update_stats(cbl, src, dst, w, op)
 
 

@@ -142,9 +142,59 @@ def shard_at(scbl: ShardedCBList, k: int) -> CBList:
     return jax.tree.map(lambda a: a[k], scbl.shards)
 
 
+def _shard_devices(mesh: Mesh, n_shards: int) -> list:
+    """The device that holds each shard: the ``"shard"`` axis lays
+    ``n_shards / axis size`` consecutive shards on each device."""
+    devs = list(mesh.devices.flat)
+    per = n_shards // len(devs)
+    return [devs[k // per] for k in range(n_shards)]
+
+
 def _restack(shards: Sequence[CBList], mesh: Mesh) -> CBList:
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *shards)
-    return jax.device_put(stacked, NamedSharding(mesh, P("shard")))
+    """Stack shard-local CBLists over the ``"shard"`` axis.  Each device's
+    slice is assembled on that device, so no shard passes through another
+    device's memory on the way."""
+    sharding = NamedSharding(mesh, P("shard"))
+    owners = _shard_devices(mesh, len(shards))
+
+    def leaf(*xs):
+        parts = [jnp.stack([jax.device_put(x, d)
+                            for x, o in zip(xs, owners) if o == d])
+                 for d in mesh.devices.flat]
+        return jax.make_array_from_single_device_arrays(
+            (len(xs),) + xs[0].shape, sharding, parts)
+
+    return jax.tree.map(leaf, *shards)
+
+
+def _per_shard(f: Callable, mesh: Mesh, *stacked):
+    """``f`` applied to every shard of ``stacked`` on the device that holds
+    it: one SPMD program, vmapped over each device's local shards."""
+    return compat.shard_map(jax.vmap(f), mesh=mesh, in_specs=P("shard"),
+                            out_specs=P("shard"), check_vma=False)(*stacked)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "num_vertices", "num_blocks", "block_width", "vertex_capacity"))
+def _build_shards(src, dst, w, valid, *, mesh: Mesh, num_vertices: int,
+                  num_blocks: int, block_width: int,
+                  vertex_capacity: int) -> CBList:
+    """Bulk-load stacked ``[S, E]`` COO slices into stacked shard CBLists."""
+    def build(s, d, ww, v):
+        return build_from_coo(s, d, ww, num_vertices=num_vertices,
+                              num_blocks=num_blocks, block_width=block_width,
+                              vertex_capacity=vertex_capacity, valid=v)
+
+    return _per_shard(build, mesh, src, dst, w, valid)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "num_blocks",
+                                             "vertex_capacity"))
+def _grow_shards(shards: CBList, *, mesh: Mesh, num_blocks: Optional[int],
+                 vertex_capacity: Optional[int]) -> CBList:
+    return _per_shard(functools.partial(grow_cbl, num_blocks=num_blocks,
+                                        vertex_capacity=vertex_capacity),
+                      mesh, shards)
 
 
 def shard_mesh(n_shards: int) -> Mesh:
@@ -196,21 +246,21 @@ def shard_cbl(cbl: CBList, n_shards: int, mesh: Optional[Mesh] = None,
     owner_shard = np.where(valid, vs[np.clip(s, 0, nvc - 1)], -1)
     per_idx = [np.nonzero(owner_shard == k)[0] for k in range(n_shards)]
     cap = max(1, max(len(ix) for ix in per_idx))
-    shards = []
-    for ix in per_idx:
-        sk = np.zeros(cap, np.int32)
-        dk = np.zeros(cap, np.int32)
-        wk = np.zeros(cap, np.float32)
-        vk = np.zeros(cap, bool)
-        sk[:len(ix)], dk[:len(ix)] = s[ix], d[ix]
-        wk[:len(ix)], vk[:len(ix)] = w[ix], True
-        shards.append(build_from_coo(
-            jnp.asarray(sk), jnp.asarray(dk), jnp.asarray(wk),
-            num_vertices=n_live, num_blocks=nb_shard,
-            block_width=bw, vertex_capacity=nvc, valid=jnp.asarray(vk)))
     if mesh is None:
         mesh = shard_mesh(n_shards)
-    stacked = _restack(shards, mesh)
+    sk = np.zeros((n_shards, cap), np.int32)
+    dk = np.zeros((n_shards, cap), np.int32)
+    wk = np.zeros((n_shards, cap), np.float32)
+    vk = np.zeros((n_shards, cap), bool)
+    for k, ix in enumerate(per_idx):
+        sk[k, :len(ix)], dk[k, :len(ix)] = s[ix], d[ix]
+        wk[k, :len(ix)], vk[k, :len(ix)] = w[ix], True
+    # each device receives its shards' slices from the host and bulk-loads
+    # them itself
+    stacked = _build_shards(
+        *jax.device_put((sk, dk, wk, vk), NamedSharding(mesh, P("shard"))),
+        mesh=mesh, num_vertices=n_live, num_blocks=nb_shard, block_width=bw,
+        vertex_capacity=nvc)
     v_shard = jax.device_put(plan.vertex_shard, NamedSharding(mesh, P()))
     return ShardedCBList(shards=stacked, v_shard=v_shard, mesh=mesh), plan
 
@@ -323,7 +373,7 @@ def _sharded_sweep(scbl: ShardedCBList, x: jax.Array, active, sweep: Callable,
                 jax.vmap(lambda c: sweep(c, xx, None))(shards_local))
 
         f = compat.shard_map(body, mesh=mesh, in_specs=(P("shard"), P()),
-                             out_specs=P(), check_rep=False)
+                             out_specs=P(), check_vma=False)
         return f(scbl.shards, x)
 
     def body(shards_local: CBList, xx, act):
@@ -331,7 +381,7 @@ def _sharded_sweep(scbl: ShardedCBList, x: jax.Array, active, sweep: Callable,
             jax.vmap(lambda c: sweep(c, xx, act))(shards_local))
 
     f = compat.shard_map(body, mesh=mesh, in_specs=(P("shard"), P(), P()),
-                         out_specs=P(), check_rep=False)
+                         out_specs=P(), check_vma=False)
     return f(scbl.shards, x, active)
 
 
@@ -359,7 +409,7 @@ def sharded_runs_sweep(runs, mesh, x: jax.Array, active, sweep: Callable,
                 jax.vmap(lambda g: sweep(g, xx, None))(runs_local))
 
         f = compat.shard_map(body, mesh=mesh, in_specs=(P("shard"), P()),
-                             out_specs=P(), check_rep=False)
+                             out_specs=P(), check_vma=False)
         return f(runs, x)
 
     def body(runs_local, xx, act):
@@ -367,7 +417,7 @@ def sharded_runs_sweep(runs, mesh, x: jax.Array, active, sweep: Callable,
             jax.vmap(lambda g: sweep(g, xx, act))(runs_local))
 
     f = compat.shard_map(body, mesh=mesh, in_specs=(P("shard"), P(), P()),
-                         out_specs=P(), check_rep=False)
+                         out_specs=P(), check_vma=False)
     return f(runs, x, active)
 
 
@@ -443,6 +493,33 @@ def _owner_counts(v_shard: jax.Array, src: jax.Array, op: jax.Array,
         active.astype(jnp.int32), jnp.where(active, owner, n_shards),
         num_segments=n_shards + 1)[:n_shards]
     return owner, counts
+
+
+@functools.partial(jax.jit, static_argnames=("n_shards",))
+def _insert_block_demand(shards: CBList, v_shard: jax.Array, src: jax.Array,
+                         op: jax.Array, n_shards: int) -> jax.Array:
+    """New blocks per shard: an upper bound on the blocks the batch's INSERT
+    records open on each shard.
+
+    Per source vertex: inserts past its tail block's slack, in whole blocks.
+    Deletes only add slack, and spill rounds fill a tail before opening a
+    block, so the bound holds for every routing of the batch."""
+    from repro.core.updates import INSERT
+    nvc = v_shard.shape[0]
+    ins = (op == INSERT) & (src >= 0) & (src < nvc)
+    c = jax.ops.segment_sum(ins.astype(jnp.int32), jnp.where(ins, src, nvc),
+                            num_segments=nvc + 1)[:nvc]
+
+    def per_shard(cbl: CBList, k: jax.Array):
+        st = cbl.store
+        B = st.block_width
+        tail = cbl.v_tail
+        slack = jnp.where(tail != NULL, B - st.count[jnp.maximum(tail, 0)], 0)
+        need = -(-jnp.maximum(c - slack, 0) // B)
+        return jnp.where(v_shard == k, need, 0).sum()
+
+    return jax.vmap(per_shard)(shards,
+                               jnp.arange(n_shards, dtype=jnp.int32))
 
 
 @jax.jit
@@ -551,7 +628,8 @@ def _attribute_shard_upserts(sp, counts: np.ndarray, lanes_per_shard: int,
 
 def sharded_batch_update_stats(scbl: ShardedCBList, src: jax.Array,
                                dst: jax.Array, w: Optional[jax.Array] = None,
-                               op: Optional[jax.Array] = None
+                               op: Optional[jax.Array] = None,
+                               grow_factor: int = 2
                                ) -> Tuple[ShardedCBList, UpdateStats]:
     """Owner-compacted parallel BatchUpdate: route, pack, fused upsert.
 
@@ -559,7 +637,11 @@ def sharded_batch_update_stats(scbl: ShardedCBList, src: jax.Array,
     op mask — S × O(batch) work, the measured write-path collapse (ROADMAP:
     545 -> ~49 updates/s at 2 shards).  Now:
 
-      1. one jitted pass computes owners + per-shard active counts;
+      1. one jitted pass computes owners + per-shard active counts; where a
+         shard has fewer free blocks than records, one more bounds the
+         blocks its inserts open, and a shard whose free blocks cannot meet
+         that grows every shard first (by ``grow_factor``, at least to the
+         deficit) — the caller sees the grow as a larger returned store;
       2. :func:`repro.core.tuner.choose_route_plan` picks the per-shard lane
          capacity (power-of-two bucketed, ceiling-clamped so jit caches stay
          bounded) and the spill-round count from the measured skew;
@@ -597,7 +679,23 @@ def sharded_batch_update_stats(scbl: ShardedCBList, src: jax.Array,
 
     with obs.span("flush.route", cat="shard", lanes=L):
         owner, counts = _owner_counts(scbl.v_shard, src, op, S)
-        counts_np = np.asarray(counts)
+        counts_np, free = jax.device_get((counts,
+                                          scbl.shards.store.free_top))
+        # a shard's pool is a 1/S slice of the store: skewed inserts can
+        # exhaust it while the others have room.  Each record opens at most
+        # one block, so only a shard with fewer free blocks than records
+        # needs the exact demand; one that cannot meet it grows every shard
+        # (uniform shapes) before the upsert, so a sharded store drops
+        # nothing the batch could place
+        if (counts_np > free).any():
+            need = np.asarray(_insert_block_demand(scbl.shards, scbl.v_shard,
+                                                   src, op, S))
+            deficit = int((need - free).max())
+            if deficit > 0:
+                nb = scbl.num_blocks
+                scbl = grow_sharded(
+                    scbl, num_blocks=max(nb * grow_factor, nb + deficit))
+                obs.counter("flush.skew_grows").inc()
         max_c = int(counts_np.max())
         route = choose_route_plan(S, L, max_records=max_c,
                                   total_records=int(counts_np.sum()))
@@ -784,17 +882,15 @@ def grow_sharded(scbl: ShardedCBList, num_blocks: Optional[int] = None,
     a fixed-shape pytree).  ``num_blocks`` is the per-shard target.  New
     vertex ids are assigned to shards round-robin — they carry no edges yet,
     so any owner is balanced."""
-    shards = [grow_cbl(shard_at(scbl, k), num_blocks=num_blocks,
-                       vertex_capacity=vertex_capacity)
-              for k in range(scbl.n_shards)]
+    shards = _grow_shards(scbl.shards, mesh=scbl.mesh, num_blocks=num_blocks,
+                          vertex_capacity=vertex_capacity)
     v_shard = scbl.v_shard
     nvc = scbl.capacity_vertices
     if vertex_capacity is not None and vertex_capacity > nvc:
         fresh = (jnp.arange(vertex_capacity - nvc, dtype=jnp.int32)
                  % scbl.n_shards)
         v_shard = jnp.concatenate([v_shard, fresh])
-    return ShardedCBList(shards=_restack(shards, scbl.mesh),
-                         v_shard=v_shard, mesh=scbl.mesh)
+    return ShardedCBList(shards=shards, v_shard=v_shard, mesh=scbl.mesh)
 
 
 @jax.jit

@@ -2,8 +2,9 @@
 
 Each kernel directory ships kernel.py (pl.pallas_call + BlockSpec VMEM
 tiling), ops.py (jit'd dispatching wrapper) and ref.py (pure-jnp oracle).
-All kernels validate in interpret mode on CPU; the TPU path is selected via
-``impl="pallas"`` (the tuner's All-Soft / Hybrid strategies).
+All kernels validate under ``impl="pallas_interpret"`` on CPU;
+``impl="pallas"`` is the compiled Mosaic kernel, which needs a TPU (the
+tuner's All-Soft / Hybrid strategies).
 """
 from repro.kernels.segment_matmul import segment_matmul, segment_sum_ref
 from repro.kernels.block_gather import gather_rows, block_gather_ref

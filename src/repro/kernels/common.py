@@ -67,14 +67,3 @@ def pad_sorted_stream(seg: jax.Array, num_rows: int, rows_per_block: int,
     out_idx = jnp.minimum(jnp.searchsorted(cum_nt, t, side="right"),
                           nblk - 1).astype(jnp.int32)
     return out_idx, perm, rows_p, NT
-
-
-def apply_perm(perm: jax.Array, data: jax.Array, fill=0) -> jax.Array:
-    """Gather data rows through ``perm`` (holes -> fill)."""
-    E = data.shape[0]
-    safe = jnp.minimum(perm, E - 1)
-    out = data[safe]
-    hole = (perm == E)
-    if data.ndim == 1:
-        return jnp.where(hole, fill, out)
-    return jnp.where(hole[:, None], fill, out)

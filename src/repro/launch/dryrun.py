@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: every cell's
@@ -22,6 +19,7 @@ Usage:
 """
 import argparse
 import gzip
+import os
 import json
 import time
 import traceback
@@ -29,10 +27,7 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs import registry
-from repro.distributed.sharding import (out_shardings_for_cell,
-                                        shardings_for_cell)
 from repro.launch import hlo_cost
 from repro.launch.mesh import make_production_mesh
 
@@ -50,23 +45,25 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
     if out_path.exists() and not force:
         return json.loads(out_path.read_text())
 
+    # imported here: the graph package makes arrays at import, which starts
+    # the backend, and main() must set the host device count before that
+    from repro.distributed.sharding import (out_shardings_for_cell,
+                                            shardings_for_cell)
     t0 = time.time()
     cb = registry.build_cell(arch, shape, opt=(mesh_name if opt else ""))
     mesh = make_production_mesh(multi_pod=multi_pod)
     in_sh = shardings_for_cell(mesh, cb)
 
     out_sh = out_shardings_for_cell(mesh, cb, in_sh)
-    # compat.set_mesh: jax.set_mesh where it exists, else the Mesh context
-    # manager — either way shard_map and bare-PartitionSpec constraints
-    # resolve against this mesh
-    with compat.set_mesh(mesh):
+    # shard_map and bare-PartitionSpec constraints resolve against this mesh
+    with jax.set_mesh(mesh):
         lowered = jax.jit(cb.step_fn, in_shardings=in_sh,
                           out_shardings=out_sh).lower(*cb.arg_specs)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
 
     hlo_text = compiled.as_text()
     parsed = hlo_cost.parse_hlo(hlo_text)
@@ -111,6 +108,9 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
 
 
 def main():
+    # 512 host devices for the production meshes; set before the backend
+    # starts, which the first device query below does
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

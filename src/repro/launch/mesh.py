@@ -7,7 +7,7 @@ Multi-pod: 2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
 tensor/expert parallel.
 
 Functions, not module constants: importing this module never touches jax
-device state (the dry-run forces 512 host devices *before* any jax import).
+device state (the dry-run forces 512 host devices before the backend starts).
 """
 from __future__ import annotations
 

@@ -448,12 +448,25 @@ class GraphService:
         # host sync and the grow-retry loop
         with obs.span("flush.upsert", cat="flush",
                       lanes=int(src2.shape[0]), retry=0):
-            new_cbl, ustats = batch_update_stats(cbl, src2, dst2, w2, op2)
+            new_cbl, ustats = self._upsert(cbl, src2, dst2, w2, op2)
         self._shadow = _ShadowFlush(
             records=(s, d, w, op, valid), watermark=watermark, pre_cbl=cbl,
             new_cbl=new_cbl, ustats=ustats, src2=src2, dst2=dst2, w2=w2,
             op2=op2, n_ins=n_ins, net_deletes=net_deletes,
             sealed_before=sealed_before)
+
+    def _upsert(self, cbl, src2, dst2, w2, op2):
+        """One upsert attempt.  A sharded store grows itself when a skewed
+        batch would overflow one shard's pool (by the policy's factor):
+        that counts as a grow here like the service's own."""
+        new_cbl, ustats = batch_update_stats(
+            cbl, src2, dst2, w2, op2, grow_factor=self._policy.grow_factor)
+        if _num_blocks(new_cbl) > _num_blocks(cbl):
+            self.stats.grows += 1
+            obs.decision("maint.decide", action="grow", phase="upsert",
+                         reason="shard pool below its routed inserts",
+                         num_blocks=_num_blocks(new_cbl))
+        return new_cbl, ustats
 
     def _finish(self) -> FlushReport:
         sh = self._shadow
@@ -485,7 +498,7 @@ class GraphService:
             self.stats.grows += 1
             with obs.span("flush.upsert", cat="flush",
                           lanes=int(src2.shape[0]), retry=grow_retries):
-                new_cbl, ustats = batch_update_stats(cbl, src2, dst2, w2, op2)
+                new_cbl, ustats = self._upsert(cbl, src2, dst2, w2, op2)
         cbl = new_cbl
         sealed_before = sh.sealed_before
         if sealed_before is not None:

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.data import rmat_edges, sasrec_batches, token_stream, update_stream
 from repro.launch.hlo_cost import parse_hlo
 
@@ -47,7 +46,7 @@ def test_hlo_parser_flops_exact_on_scan():
     expected = 2 * 128 * 256 * 256 * 8
     assert abs(parsed["flops"] - expected) / expected < 1e-6
     # raw XLA count misses the trip count (the reason this parser exists)
-    raw = compat.cost_analysis(compiled)["flops"]
+    raw = compiled.cost_analysis()["flops"]
     assert raw < parsed["flops"] / 4
 
 

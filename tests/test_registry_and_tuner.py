@@ -1,13 +1,15 @@
 """Cell registry: 40 cells, skips documented, spec/param-count sanity.
 Adaptation-layer tuner: strategy selection matches the paper's decision
 rules."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import registry
-from repro.core import SystemProbe, build_from_coo, choose_plan
+from repro.core import build_from_coo, choose_plan, system_probe
 from repro.core import batch_update
 
 
@@ -65,7 +67,8 @@ def test_tuner_switches_after_fragmentation():
         cbl = batch_update(cbl, jnp.arange(8, dtype=jnp.int32) * 2,
                            jnp.full((8,), 100 + i, jnp.int32) % 16 + i)
     plan = choose_plan(cbl, "scan_all",
-                       SystemProbe(block_fetch_overhead_us=5.0))
+                       dataclasses.replace(system_probe(),
+                                           block_fetch_overhead_us=5.0))
     assert plan.strategy != "all_hard"
     # frontier tasks always use the vertex partition (paper §5.2)
     plan_f = choose_plan(cbl, "frontier")
@@ -76,7 +79,7 @@ def test_tuner_switches_after_fragmentation():
 
 def test_tuner_lookahead_scales_with_block_bytes():
     from repro.core.tuner import choose_lookahead
-    probe = SystemProbe()
+    probe = system_probe()
     small = choose_lookahead(probe, 1024)
     large = choose_lookahead(probe, 1 << 20)
     assert small >= large

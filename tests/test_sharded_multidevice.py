@@ -66,7 +66,9 @@ print("SHARD_MULTIDEV_OK")
 
 
 def test_sharded_equivalence_8_host_devices():
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # the children run on host devices: a child that reached for the chip
+    # would contend with this process, which may hold it
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=560,
                          cwd=REPO)

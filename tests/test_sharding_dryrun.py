@@ -20,7 +20,9 @@ REPO = Path(__file__).resolve().parent.parent
 @pytest.mark.parametrize("arch,shape", [("gin-tu", "molecule"),
                                         ("sasrec", "serve_p99")])
 def test_dryrun_cell_compiles_both_meshes(arch, shape, tmp_path):
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # the children run on host devices: a child that reached for the chip
+    # would contend with this process, which may hold it
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", "both", "--out", str(tmp_path)],
@@ -53,7 +55,9 @@ np.testing.assert_array_equal(np.asarray(back["w"]),
                               np.arange(64, dtype=np.float32).reshape(8, 8))
 print("ELASTIC_OK")
 """
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # the children run on host devices: a child that reached for the chip
+    # would contend with this process, which may hold it
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=240)
     assert res.returncode == 0, res.stdout + res.stderr
@@ -88,7 +92,9 @@ err = float(jnp.abs(y_base - y_ep).max())
 assert err < 1e-4, err
 print("EP_OK", err)
 """
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # the children run on host devices: a child that reached for the chip
+    # would contend with this process, which may hold it
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=420)
     assert res.returncode == 0, res.stdout + res.stderr

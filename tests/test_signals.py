@@ -10,8 +10,8 @@ import pytest
 import repro.obs as obs
 from repro.core import DELETE, INSERT
 from repro.core.tuner import (MIN_SIGNAL_SAMPLES, SERVE_REPLICA_TARGET_UTIL,
-                              ServePlan, SystemProbe, choose_plan,
-                              choose_serve_plan)
+                              ServePlan, choose_plan, choose_serve_plan,
+                              system_probe)
 from repro.data import rmat_edges
 from repro.obs import (EMPTY_VIEW, SignalBus, SignalSummary, SignalView,
                        SloTracker)
@@ -117,7 +117,7 @@ def test_bus_window_is_bounded():
 # ---- choose_serve_plan adaptation (acceptance: injected read pressure) ----
 
 def test_choose_serve_plan_sizes_replicas_from_read_pressure(live_obs):
-    probe = SystemProbe()
+    probe = system_probe()
     cap = probe.replica_read_lanes_per_s * SERVE_REPLICA_TARGET_UTIL
     view = view_of(read_lanes_per_s=3.2 * cap)
     plan = choose_serve_plan(100.0, probe=probe, signals=view, max_replicas=8)
@@ -132,7 +132,7 @@ def test_choose_serve_plan_sizes_replicas_from_read_pressure(live_obs):
 
 
 def test_choose_serve_plan_replicas_clamped_and_guarded():
-    probe = SystemProbe()
+    probe = system_probe()
     cap = probe.replica_read_lanes_per_s * SERVE_REPLICA_TARGET_UTIL
     # clamp: demand for 40 replicas, only 2 devices
     plan = choose_serve_plan(10.0, probe=probe,

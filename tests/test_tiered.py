@@ -55,7 +55,12 @@ def test_program_equivalence(n_shards, impl):
     for fn in (lambda g: bfs(g, jnp.int32(0), impl=impl),
                lambda g: sssp(g, jnp.int32(1), impl=impl),
                lambda g: connected_components(g, impl=impl)):
-        assert np.array_equal(np.asarray(fn(tg)), np.asarray(fn(ref)))
+        if impl == "xla":
+            assert np.array_equal(np.asarray(fn(tg)), np.asarray(fn(ref)))
+        else:   # min-semiring sweeps: the MXU kernels only sum
+            for g in (tg, ref):
+                with pytest.raises(ValueError, match="only sums"):
+                    fn(g)
 
 
 @pytest.mark.parametrize("n_shards", [1, 2])
