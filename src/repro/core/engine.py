@@ -152,18 +152,20 @@ def _process_edge_push(cbl: CBList, x: jax.Array,
     _check_combine(combine, impl)
     st = cbl.store
     nv = cbl.capacity_vertices
-    owner_safe = jnp.maximum(st.owner, 0)
-    xs = _gather_values(x, owner_safe, impl)             # [NB] per-block src value
-    mask = lane_mask(st)
-    if active is not None:
-        mask = mask & active[owner_safe][:, None]
-    msg = dense_f(xs[:, None], st.vals)                  # [NB, B]
-    seg = jnp.where(mask, st.keys, nv)                   # PAD/out-of-range drop
     sr = SEMIRINGS[combine]
-    msg = jnp.where(mask, msg, sr.fill)
-    if combine == "sum":
-        return _segment_sum(msg.ravel(), seg.ravel(), nv, impl)
-    return sr.segment_reduce(msg.ravel(), seg.ravel(), num_segments=nv)
+    with jax.named_scope("sweep.gather"):
+        owner_safe = jnp.maximum(st.owner, 0)
+        xs = _gather_values(x, owner_safe, impl)     # [NB] per-block src value
+        mask = lane_mask(st)
+        if active is not None:
+            mask = mask & active[owner_safe][:, None]
+        msg = dense_f(xs[:, None], st.vals)          # [NB, B]
+        seg = jnp.where(mask, st.keys, nv)           # PAD/out-of-range drop
+        msg = jnp.where(mask, msg, sr.fill)
+    with jax.named_scope("sweep.combine"):
+        if combine == "sum":
+            return _segment_sum(msg.ravel(), seg.ravel(), nv, impl)
+        return sr.segment_reduce(msg.ravel(), seg.ravel(), num_segments=nv)
 
 
 def process_edge_pull(cbl, x: jax.Array,
@@ -201,19 +203,21 @@ def _process_edge_pull(cbl: CBList, x: jax.Array,
     _check_combine(combine, impl)
     st = cbl.store
     nv = cbl.capacity_vertices
-    mask = lane_mask(st)
-    dst_safe = jnp.clip(st.keys, 0, nv - 1)
-    xd = _gather_values(x, dst_safe, impl)               # [NB, B] random gather
-    if active_dst is not None:
-        mask = mask & active_dst[dst_safe]
-    msg = dense_f(xd, st.vals)
-    owner_seg = jnp.where(st.owner == NULL, nv, st.owner)
     sr = SEMIRINGS[combine]
-    msg = jnp.where(mask, msg, sr.fill)
-    per_blk = sr.lane_reduce(msg, axis=1)
-    if combine == "sum":
-        return _segment_sum(per_blk, owner_seg, nv, impl)
-    return sr.segment_reduce(per_blk, owner_seg, num_segments=nv)
+    with jax.named_scope("sweep.gather"):
+        mask = lane_mask(st)
+        dst_safe = jnp.clip(st.keys, 0, nv - 1)
+        xd = _gather_values(x, dst_safe, impl)       # [NB, B] random gather
+        if active_dst is not None:
+            mask = mask & active_dst[dst_safe]
+        msg = dense_f(xd, st.vals)
+        msg = jnp.where(mask, msg, sr.fill)
+    with jax.named_scope("sweep.combine"):
+        owner_seg = jnp.where(st.owner == NULL, nv, st.owner)
+        per_blk = sr.lane_reduce(msg, axis=1)
+        if combine == "sum":
+            return _segment_sum(per_blk, owner_seg, nv, impl)
+        return sr.segment_reduce(per_blk, owner_seg, num_segments=nv)
 
 
 def process_edge_push_feat(cbl, x: jax.Array,
@@ -248,15 +252,19 @@ def _process_edge_push_feat(cbl: CBList, x: jax.Array,
                             impl: str = "xla") -> jax.Array:
     st = cbl.store
     nv = cbl.capacity_vertices
-    owner_safe = jnp.maximum(st.owner, 0)
-    xs = _gather_values(x, owner_safe, impl)             # [NB, F]
-    mask = lane_mask(st)
-    if active is not None:
-        mask = mask & active[owner_safe][:, None]
-    scale = st.vals if weighted else jnp.ones_like(st.vals)
-    msg = xs[:, None, :] * jnp.where(mask, scale, 0.0)[:, :, None]  # [NB,B,F]
-    seg = jnp.where(mask, st.keys, nv)
-    return _segment_sum(msg.reshape(-1, x.shape[1]), seg.ravel(), nv, impl)
+    with jax.named_scope("sweep.gather"):
+        owner_safe = jnp.maximum(st.owner, 0)
+        xs = _gather_values(x, owner_safe, impl)     # [NB, F]
+        mask = lane_mask(st)
+        if active is not None:
+            mask = mask & active[owner_safe][:, None]
+        scale = st.vals if weighted else jnp.ones_like(st.vals)
+        # [NB, B, F]
+        msg = xs[:, None, :] * jnp.where(mask, scale, 0.0)[:, :, None]
+        seg = jnp.where(mask, st.keys, nv)
+    with jax.named_scope("sweep.combine"):
+        return _segment_sum(msg.reshape(-1, x.shape[1]), seg.ravel(), nv,
+                            impl)
 
 
 def out_degrees(cbl: CBList) -> jax.Array:

@@ -263,7 +263,11 @@ def _step(ctx: ProgramContext, prog: VertexProgram, state, frontier,
         x = sw.pre(ctx, new) if sw.pre is not None else new
         act = frontier if (frontier is not None and sw.use_frontier) else None
         acc = _run_sweep(ctx.cbl, sw, x, act, impl)
-        new = sw.apply(ctx, new, acc) if sw.apply is not None else acc
+        if sw.apply is not None:
+            with jax.named_scope("sweep.apply"):
+                new = sw.apply(ctx, new, acc)
+        else:
+            new = acc
     nf = None
     if frontier is not None:
         nf = (prog.frontier_next(ctx, state, new)
@@ -338,9 +342,9 @@ def _retract_unsupported(ctx: ProgramContext, prog: VertexProgram, state,
 
 
 @functools.partial(jax.jit, static_argnames=("prog", "impl", "max_iters",
-                                             "static_kv", "return_stats"))
+                                             "static_kv"))
 def _run_program(cbl, warm, params, *, prog: VertexProgram, impl: str,
-                 max_iters: int, static_kv, return_stats: bool):
+                 max_iters: int, static_kv):
     nv = cbl.capacity_vertices
     live = jnp.arange(nv) < cbl.n_vertices
     merged = dict(params)
@@ -364,7 +368,7 @@ def _run_program(cbl, warm, params, *, prog: VertexProgram, impl: str,
 
     state, iters = _fixpoint(ctx, prog, state, frontier, max_iters, impl)
     out = prog.finalize(ctx, state) if prog.finalize is not None else state
-    return (out, iters) if return_stats else out
+    return out, iters
 
 
 def run_program(cbl, prog: VertexProgram, *, warm=None,
@@ -381,7 +385,8 @@ def run_program(cbl, prog: VertexProgram, *, warm=None,
     program's ``task`` metadata.  ``**params`` are forwarded to the program
     hooks through ``ctx.params`` — names in ``prog.static_params`` become
     jit-static, the rest are traced.  With ``return_stats`` the executor
-    also returns the iteration count the fixpoint took.
+    also returns the iteration count the fixpoint took; one compiled
+    program serves both forms.
     """
     if impl is None:
         from repro.core.tuner import choose_engine_impl
@@ -399,6 +404,6 @@ def run_program(cbl, prog: VertexProgram, *, warm=None,
     # jit-honest locality profile: taken here at the host-side entry point,
     # outside the traced sweep (one flag check when obs is off)
     obs.record_sweep(cbl, task=prog.task)
-    return _run_program(cbl, warm, params, prog=prog, impl=impl,
-                        max_iters=int(max_iters), static_kv=static_kv,
-                        return_stats=return_stats)
+    out, iters = _run_program(cbl, warm, params, prog=prog, impl=impl,
+                              max_iters=int(max_iters), static_kv=static_kv)
+    return (out, iters) if return_stats else out

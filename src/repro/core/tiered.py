@@ -65,7 +65,8 @@ from repro.core.engine import (SEMIRINGS, _DEFAULT_EDGE_F, in_degrees,
                                process_edge_pull, process_edge_push,
                                process_edge_push_feat)
 from repro.core.updates import (INSERT, NOP, UpdateStats, batch_update_stats,
-                                delete_vertices, read_edges, upsert_edges)
+                                delete_vertices, read_edges_stats,
+                                upsert_edges)
 
 # delta re-size policy at seal time: hot block demand gets this slack, then
 # rounds up to a power of two (bounded jit-recompile churn) with this floor
@@ -274,9 +275,18 @@ def tiered_in_degrees(tg: TieredGraph) -> jax.Array:
 def tiered_read_edges(tg: TieredGraph, qsrc: jax.Array, qdst: jax.Array
                       ) -> Tuple[jax.Array, jax.Array]:
     """Batched read_edge over both tiers (at most one can find an edge)."""
-    f1, w1 = read_edges(tg.delta, qsrc, qdst)
+    found, w, _ = tiered_read_edges_stats(tg, qsrc, qdst)
+    return found, w
+
+
+def tiered_read_edges_stats(tg: TieredGraph, qsrc: jax.Array,
+                            qdst: jax.Array):
+    """:func:`tiered_read_edges` and the delta's chain-walk
+    :class:`~repro.core.updates.LocateStats` (the run tier is a CSR
+    lookup, with no chain to walk)."""
+    f1, w1, walk = read_edges_stats(tg.delta, qsrc, qdst)
     if tg.run_capacity == 0:
-        return f1, w1
+        return f1, w1, walk
     if tg.is_sharded:
         fs, ws = jax.vmap(csr_query, in_axes=(0, None, None))(
             tg.runs, qsrc, qdst)
@@ -284,7 +294,7 @@ def tiered_read_edges(tg: TieredGraph, qsrc: jax.Array, qdst: jax.Array
         w2 = jnp.where(fs, ws, 0.0).sum(axis=0)
     else:
         f2, w2 = csr_query(tg.runs, qsrc, qdst)
-    return f1 | f2, jnp.where(f1, w1, w2)
+    return f1 | f2, jnp.where(f1, w1, w2), walk
 
 
 def tiered_sample_neighbors(tg: TieredGraph, verts: jax.Array,

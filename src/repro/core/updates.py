@@ -17,6 +17,7 @@ Update protocol (all pure, jit-compatible, fixed shapes):
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -29,6 +30,19 @@ from repro.core.cblist import CBList, _exclusive_cumsum
 INSERT = 1
 DELETE = -1
 NOP = 0
+
+
+class LocateStats(NamedTuple):
+    """The work of one :func:`_locate` chain walk.
+
+    Every step gathers one block row for each of the walk's queries, so
+    ``lane_steps / lane_slots`` is the share of the gathered rows that
+    belonged to a query still walking.  Int32: ``lane_slots`` holds up to
+    a walk of 32,767 steps over 65,536 queries.
+    """
+    steps: jax.Array        # i32[] the walk loop's trip count
+    lane_steps: jax.Array   # i32[] queries still walking, summed over steps
+    lane_slots: jax.Array   # i32[] steps x the queries gathered each step
 
 
 class UpdateStats(NamedTuple):
@@ -44,13 +58,16 @@ class UpdateStats(NamedTuple):
     dropped_edges: jax.Array    # i32[] inserts not placed (allocator full)
     applied_inserts: jax.Array  # i32[] inserts placed
     applied_deletes: jax.Array  # i32[] deletes that located + removed an edge
+    locate: LocateStats         # the delete phase's chain walk
 
 
 def _locate(cbl: CBList, qsrc: jax.Array, qdst: jax.Array, active: jax.Array):
-    """Chain-walk locate of (src, dst): returns (found_blk, found_lane).
+    """Chain-walk locate of (src, dst): returns (found_blk, found_lane,
+    :class:`LocateStats`).
 
     Vectorized FindNeighbor: each step binary-searches one block per query
-    (blocks are internally sorted, PAD-padded) and follows the chain.
+    (blocks are internally sorted, PAD-padded) and follows the chain; the
+    walk runs until every query's chain has ended or hit.
     Not-found -> (-1, -1).
     """
     st = cbl.store
@@ -62,10 +79,12 @@ def _locate(cbl: CBList, qsrc: jax.Array, qdst: jax.Array, active: jax.Array):
     vsrch = jax.vmap(srch)
 
     def body(state):
-        cur, fblk, flane = state
+        cur, fblk, flane, steps, lane_steps = state
+        walking = (cur != NULL).sum(dtype=jnp.int32)
         safe = jnp.maximum(cur, 0)
         rows = st.keys[safe]
-        pos = vsrch(rows, qdst).astype(jnp.int32)
+        with jax.named_scope("locate.search"):
+            pos = vsrch(rows, qdst).astype(jnp.int32)
         inb = pos < B
         val = jnp.take_along_axis(rows, jnp.minimum(pos, B - 1)[:, None],
                                   axis=1)[:, 0]
@@ -74,19 +93,23 @@ def _locate(cbl: CBList, qsrc: jax.Array, qdst: jax.Array, active: jax.Array):
         fblk = jnp.where(new, cur, fblk)
         flane = jnp.where(new, pos, flane)
         cur = jnp.where(hit | (cur == NULL), NULL, st.nxt[safe])
-        return cur, fblk, flane
+        return cur, fblk, flane, steps + 1, lane_steps + walking
 
     def cond(state):
-        cur, _, _ = state
-        return jnp.any(cur != NULL)
+        return jnp.any(state[0] != NULL)
 
-    cur0 = jnp.where(active, cbl.v_head[jnp.clip(qsrc, 0, cbl.capacity_vertices - 1)],
-                     NULL)
-    init = (cur0,
-            jnp.full_like(qsrc, NULL),
-            jnp.full_like(qsrc, NULL))
-    _, fblk, flane = jax.lax.while_loop(cond, body, init)
-    return fblk, flane
+    with jax.named_scope("locate"):
+        cur0 = jnp.where(
+            active, cbl.v_head[jnp.clip(qsrc, 0, cbl.capacity_vertices - 1)],
+            NULL)
+        init = (cur0,
+                jnp.full_like(qsrc, NULL),
+                jnp.full_like(qsrc, NULL),
+                jnp.int32(0), jnp.int32(0))
+        _, fblk, flane, steps, lane_steps = jax.lax.while_loop(cond, body,
+                                                               init)
+    return fblk, flane, LocateStats(steps=steps, lane_steps=lane_steps,
+                                    lane_slots=steps * qsrc.shape[0])
 
 
 def read_edges(cbl, qsrc: jax.Array, qdst: jax.Array
@@ -96,23 +119,43 @@ def read_edges(cbl, qsrc: jax.Array, qdst: jax.Array
     Accepts a CBList or a ShardedCBList (fan-out: only the owning shard can
     find an edge) — like every update entry point in this module.
     """
+    found, w, _ = read_edges_stats(cbl, qsrc, qdst)
+    return found, w
+
+
+def read_edges_stats(cbl, qsrc: jax.Array, qdst: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array, LocateStats]:
+    """:func:`read_edges` and the :class:`LocateStats` of its chain walk
+    (summed over shards; a tiered store's walk is its delta's)."""
     if not isinstance(cbl, CBList):
-        from repro.core.tiered import TieredGraph, tiered_read_edges
+        from repro.core.tiered import TieredGraph, tiered_read_edges_stats
         if isinstance(cbl, TieredGraph):
-            return tiered_read_edges(cbl, qsrc, qdst)
-        from repro.distributed.graph import sharded_read_edges
-        return sharded_read_edges(cbl, qsrc, qdst)
+            return tiered_read_edges_stats(cbl, qsrc, qdst)
+        from repro.distributed.graph import sharded_read_edges_stats
+        return sharded_read_edges_stats(cbl, qsrc, qdst)
     return _read_edges(cbl, qsrc, qdst)
 
 
 @jax.jit
 def _read_edges(cbl: CBList, qsrc: jax.Array, qdst: jax.Array
-                ) -> Tuple[jax.Array, jax.Array]:
-    fblk, flane = _locate(cbl, qsrc, qdst,
-                          jnp.ones(qsrc.shape, bool))
+                ) -> Tuple[jax.Array, jax.Array, LocateStats]:
+    fblk, flane, walk = _locate(cbl, qsrc, qdst,
+                                jnp.ones(qsrc.shape, bool))
     found = fblk != NULL
     w = cbl.store.vals[jnp.maximum(fblk, 0), jnp.maximum(flane, 0)]
-    return found, jnp.where(found, w, 0.0)
+    return found, jnp.where(found, w, 0.0), walk
+
+
+def _scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``: HLO
+    metadata only, so every program that inlines it names its ops."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args):
+            with jax.named_scope(name):
+                return fn(*args)
+        return inner
+    return wrap
 
 
 def _dedupe_first(src, dst, mask):
@@ -127,9 +170,10 @@ def _dedupe_first(src, dst, mask):
     return keep & mask
 
 
+@_scoped("upsert.delete")
 def _apply_deletes(cbl: CBList, src, dst, mask):
     mask = _dedupe_first(src, dst, mask)
-    fblk, flane = _locate(cbl, src, dst, mask)
+    fblk, flane, walk = _locate(cbl, src, dst, mask)
     fblk = jnp.where(mask, fblk, NULL)
     found = fblk != NULL
     st = cbl.store
@@ -148,9 +192,10 @@ def _apply_deletes(cbl: CBList, src, dst, mask):
                                         jnp.where(found, src, nvc),
                                         num_segments=nvc)
     return (cbl._replace(store=st, v_deg=cbl.v_deg - removed_per_v),
-            found.sum(dtype=jnp.int32))
+            found.sum(dtype=jnp.int32), walk)
 
 
+@_scoped("upsert.insert")
 def _apply_inserts(cbl: CBList, src, dst, w, mask):
     U = src.shape[0]
     st = cbl.store
@@ -289,11 +334,11 @@ def _batch_update_stats(cbl: CBList, src: jax.Array, dst: jax.Array,
         w = jnp.ones(src.shape, jnp.float32)
     if op is None:
         op = jnp.full(src.shape, INSERT, jnp.int32)
-    cbl, n_del = _apply_deletes(cbl, src, dst, op == DELETE)
+    cbl, n_del, walk = _apply_deletes(cbl, src, dst, op == DELETE)
     cbl, dropped = _apply_inserts(cbl, src, dst, w, op == INSERT)
     n_ins = (op == INSERT).sum(dtype=jnp.int32) - dropped
     return cbl, UpdateStats(dropped_edges=dropped, applied_inserts=n_ins,
-                            applied_deletes=n_del)
+                            applied_deletes=n_del, locate=walk)
 
 
 def batch_update(cbl, src: jax.Array, dst: jax.Array,
@@ -338,7 +383,7 @@ def _upsert_edges(cbl: CBList, src, dst, w=None,
         w = jnp.ones(src.shape, jnp.float32)
     if valid is None:
         valid = jnp.ones(src.shape, bool)
-    cbl, _ = _apply_deletes(cbl, src, dst, valid)
+    cbl, _, _ = _apply_deletes(cbl, src, dst, valid)
     cbl, _ = _apply_inserts(cbl, src, dst, w, valid)
     return cbl
 

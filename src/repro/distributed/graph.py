@@ -730,13 +730,11 @@ def sharded_batch_update_stats(scbl: ShardedCBList, src: jax.Array,
                                  route.n_rounds * route.lane_cap,
                                  route.n_rounds)
 
-    def _sum(field):
-        parts = [getattr(s, field).sum() for s in per_round]
-        return functools.reduce(jnp.add, parts)
-
-    agg = UpdateStats(dropped_edges=_sum("dropped_edges"),
-                      applied_inserts=_sum("applied_inserts"),
-                      applied_deletes=_sum("applied_deletes"))
+    # every count, the chain walk's too, sums over shards and rounds: each
+    # shard walks its own routed lanes, for as many steps as its own chains
+    agg = jax.tree.map(
+        lambda *xs: functools.reduce(jnp.add, [x.sum() for x in xs]),
+        *per_round)
     return dataclasses.replace(scbl, shards=shards), agg
 
 
@@ -751,13 +749,23 @@ def sharded_batch_update_stats_traced(scbl: ShardedCBList, src: jax.Array,
     return sharded_batch_update_stats(scbl, src, dst, w, op)
 
 
-@jax.jit
 def sharded_read_edges(scbl: ShardedCBList, qsrc: jax.Array, qdst: jax.Array
                        ) -> Tuple[jax.Array, jax.Array]:
     """Batched read_edge over shards: only the owner can find the edge."""
-    found, w = jax.vmap(_read_edges, in_axes=(0, None, None))(
+    found, w, _ = sharded_read_edges_stats(scbl, qsrc, qdst)
+    return found, w
+
+
+@jax.jit
+def sharded_read_edges_stats(scbl: ShardedCBList, qsrc: jax.Array,
+                             qdst: jax.Array):
+    """:func:`sharded_read_edges` and its chain walks'
+    :class:`~repro.core.updates.LocateStats`, summed over the shards (each
+    walks every query, from its own chain heads)."""
+    found, w, walk = jax.vmap(_read_edges, in_axes=(0, None, None))(
         scbl.shards, qsrc, qdst)
-    return found.any(axis=0), jnp.where(found, w, 0.0).sum(axis=0)
+    return (found.any(axis=0), jnp.where(found, w, 0.0).sum(axis=0),
+            jax.tree.map(jnp.sum, walk))
 
 
 _fused_upsert = jax.jit(jax.vmap(_upsert_edges, in_axes=(0, 0, 0, 0, 0)))

@@ -304,17 +304,19 @@ class ServeFrontend:
         #    its device work is done (or pressure recurs), then begin a new
         #    one under write pressure — begin defers the publish, so the
         #    reads below still serve the pinned epoch and never block on
-        #    the upsert
-        pressure = (self.service.pending_updates
-                    >= self.plan.flush_pending_max)
-        if self.service.flush_in_flight and (pressure
-                                             or self.service.flush_ready()):
-            self._finish_flush()
-        if pressure:
-            if self.plan.double_buffer:
-                self._begin_flush()
-            else:
-                self._flush()
+        #    the upsert.  The span holds the pending_updates read too,
+        #    which waits behind an upsert still on the device
+        with obs.span("serve.flush_control", cat="serve"):
+            pressure = (self.service.pending_updates
+                        >= self.plan.flush_pending_max)
+            if self.service.flush_in_flight and (
+                    pressure or self.service.flush_ready()):
+                self._finish_flush()
+            if pressure:
+                if self.plan.double_buffer:
+                    self._begin_flush()
+                else:
+                    self._flush()
 
         # 4. mirror a newly published snapshot across the read replicas
         self.read_plane.broadcast(self.service.snapshot)
@@ -372,7 +374,10 @@ class ServeFrontend:
             now = max(now, min(deadlines)) if deadlines else now
             self.step(now)
         if flush:
-            self._flush()
+            # flush control as in step 3: _flush's pending_updates read
+            # waits here behind an upsert still on the device
+            with obs.span("serve.flush_control", cat="serve"):
+                self._flush()
         return self._completed - done0
 
     def retune(self, now: Optional[float] = None) -> ServePlan:

@@ -48,8 +48,8 @@ from repro.core.cblist import CBList, blocks_needed, build_from_coo
 from repro.core.program import (VertexProgram, get_program, has_program,
                                 run_program)
 from repro.core.tuner import SystemProbe, choose_engine_impl, choose_plan
-from repro.core.updates import (DELETE, INSERT, NOP, batch_update_stats,
-                                read_edges)
+from repro.core.updates import (DELETE, INSERT, NOP, LocateStats,
+                                batch_update_stats, read_edges_stats)
 from repro.graph import algorithms as _builtin_programs  # noqa: F401 — registers the built-in VertexPrograms
 from repro.stream import log as ulog
 from repro.stream import maintenance as maint
@@ -68,6 +68,19 @@ def _num_blocks(cbl) -> int:
     TieredGraph reports its delta's capacity (grow only ever targets the
     mutable tier)."""
     return cbl.store.num_blocks if isinstance(cbl, CBList) else cbl.num_blocks
+
+
+def _count_walk(site: str, walk: LocateStats) -> None:
+    """The chain walk's work as ``locate.*`` counters, labelled by the
+    flush step that walked (``upsert`` or ``coalesce``).  Only when obs is
+    on, and only after the step's own host sync: the counts are then
+    already on the device, and the disabled path adds no sync."""
+    if not obs.enabled():
+        return
+    steps, lane_steps, lane_slots = (int(v) for v in jax.device_get(walk))
+    obs.counter("locate.steps", site=site).inc(steps)
+    obs.counter("locate.lane_steps", site=site).inc(lane_steps)
+    obs.counter("locate.lane_slots", site=site).inc(lane_slots)
 
 
 def _pad_warm(warm: jax.Array, capacity: int, fill) -> jax.Array:
@@ -391,14 +404,12 @@ class GraphService:
             return self._finish()
 
     def _begin(self) -> None:
-        with obs.span("flush.admission", cat="flush") as adm_rec:
+        with obs.span("flush.admission", cat="flush"):
             self._log, (s, d, w, op, valid) = ulog.drain(self._log)
             watermark = int(self._log.head)
-        obs.histogram("flush.phase_s", obs.LATENCY_BUCKETS_S,
-                      phase="admission").observe(adm_rec.get("dur", 0.0))
         cbl = self._snap.cbl
 
-        with obs.span("flush.coalesce", cat="flush") as coal_rec:
+        with obs.span("flush.coalesce", cat="flush"):
             # cross-append coalescing: the drained stream is FIFO, the last
             # op per key is the net effect (append only coalesces within one
             # batch)
@@ -412,12 +423,11 @@ class GraphService:
             # cold CC restarts.
             del_keys = keep & (op == DELETE)
             if bool(del_keys.any()):
-                found, _ = read_edges(cbl, s, d)
+                found, _, walk = read_edges_stats(cbl, s, d)
                 net_deletes = int((del_keys & found).sum())
+                _count_walk("coalesce", walk)
             else:
                 net_deletes = 0
-        obs.histogram("flush.phase_s", obs.LATENCY_BUCKETS_S,
-                      phase="coalesce").observe(coal_rec.get("dur", 0.0))
         obs.counter("flush.pending_inserts").inc(n_ins)
         obs.counter("flush.net_deletes").inc(net_deletes)
 
@@ -478,6 +488,7 @@ class GraphService:
         grow_retries = 0
         while True:
             dropped = int(obs.wait(ustats.dropped_edges, "flush.upsert.sync"))
+            _count_walk("upsert", ustats.locate)
             if dropped == 0:
                 break
             if grow_retries >= MAX_GROW_RETRIES:
@@ -513,7 +524,7 @@ class GraphService:
         # never skip a flush, only the repair statistics do).  With a
         # signal bus attached the policy is churn-adapted first, and decide
         # and apply both run under the same adapted K.
-        with obs.span("flush.maintenance", cat="flush") as maint_rec:
+        with obs.span("flush.maintenance", cat="flush"):
             policy = self._policy
             if self._signals is not None:
                 policy = policy.adapted(self._signals.view())
@@ -531,8 +542,6 @@ class GraphService:
                     self.stats.seals += 1
                 else:
                     self.stats.grows += 1
-        obs.histogram("flush.phase_s", obs.LATENCY_BUCKETS_S,
-                      phase="maintenance").observe(maint_rec.get("dur", 0.0))
 
         self._snap = snap.advance(self._snap, cbl, watermark)
         self.stats.flushes += 1
@@ -618,7 +627,10 @@ class GraphService:
         call_kw = dict(kw)
         if prog.needs_source:
             call_kw["source"] = jnp.int32(source)
-        out = run_program(cbl, prog, warm=warm, impl=impl, **call_kw)
+        out, sweeps = run_program(cbl, prog, warm=warm, impl=impl,
+                                  return_stats=True, **call_kw)
+        if obs.enabled():
+            obs.counter("program.sweeps", program=name).inc(int(sweeps))
 
         self._cache[key] = (epoch, self._deletes_applied, dict(kw), out)
         return out

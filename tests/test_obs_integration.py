@@ -171,3 +171,33 @@ def test_dump_trace_perfetto_loadable(tmp_path, live_obs):
     names = {e["name"] for e in complete}
     assert {"flush.admission", "flush.coalesce",
             "flush.upsert.shard", "flush.maintenance"} <= names
+
+
+def test_flush_control_span_holds_the_publish_wait(live_obs):
+    """Both places the frontend decides on a flush (step 3 and a drain's
+    final flush) run under ``serve.flush_control``, so the host's wait
+    there for an upsert still on the device lands in a named span."""
+    from repro.serve import UpdateBatch
+    svc = _mk_service(n_shards=1)
+    plan = ServePlan(bucket_set=(16, 32),
+                     windows={"interactive": 0.001, "standard": 0.004,
+                              "batch": 0.02},
+                     flush_pending_max=256, arrival_lanes_per_s=0.0)
+    clock = ManualClock()
+    front = ServeFrontend(svc, plan, clock=clock)
+    front.register_tenant("t0")
+    rng = np.random.default_rng(9)
+    front.submit(UpdateBatch(src=rng.integers(0, NV, 16),
+                             dst=rng.integers(0, NV, 16), tenant="t0"))
+    clock.advance(0.1)
+    front.drain(flush=True)
+    spans = {}
+    for ev in obs.tracer().events:
+        if ev.get("ph") == "X":
+            spans.setdefault(ev["name"], []).append((ev["ts"],
+                                                     ev["ts"] + ev["dur"]))
+    control = spans["serve.flush_control"]
+    assert len(control) >= 2          # at least one step, and the drain's
+    (a, b), = spans["serve.flush"]
+    assert any(lo <= a and b <= hi for lo, hi in control)
+    assert svc.epoch == 1

@@ -169,7 +169,11 @@ def test_skewed_spill_matches_oracle(n_shards, obs_on):
             "flush.spill_rounds", 0)
         assert spill >= 1, "skewed batch should exercise the spill path"
     obs.disable()
-    assert tuple(int(x) for x in st) == tuple(int(x) for x in ost)
+    # the accounting matches the oracle's; the chain-walk counts differ by
+    # design (each shard walks its own routed lanes)
+    acct = ("dropped_edges", "applied_inserts", "applied_deletes")
+    assert [int(getattr(st, k)) for k in acct] \
+        == [int(getattr(ost, k)) for k in acct]
     me = 8 * 256 * 8
     assert _edge_set(unshard(out, num_blocks=8 * 256), me) \
         == _edge_set(oracle, me)
