@@ -65,8 +65,9 @@ def run():
             # both paths the same impl — the ratio must isolate executor
             # overhead, not per-call plan resolution
             impl = choose_engine_impl(graph, prog)
-            _, iters = run_program(graph, prog, impl=impl,
+            _, stats = run_program(graph, prog, impl=impl,
                                    return_stats=True, **kw)
+            iters = stats.sweeps
             t_prog = time_fn(lambda: run_program(graph, prog, impl=impl,
                                                  **kw), iters=3)
             t_legacy = time_fn(lambda: legacy_fn(graph, impl), iters=3)

@@ -47,8 +47,10 @@ import jax
 import jax.numpy as jnp
 
 import repro.obs as obs
-from repro.core.engine import (SEMIRINGS, process_edge_pull,
-                               process_edge_push, process_edge_push_feat)
+from repro.core.cblist import CBList
+from repro.core.engine import (SEMIRINGS, _process_edge_push,
+                               process_edge_pull, process_edge_push,
+                               process_edge_push_feat, push_plan)
 
 INF = jnp.float32(jnp.inf)
 
@@ -62,13 +64,23 @@ class ProgramContext(NamedTuple):
     ``params`` the merged traced + static call parameters, and ``consts``
     whatever the program's ``setup`` hook precomputed — the loop-invariant
     home for degree vectors, masks, one-hot seeds, and friends (hoisted out
-    of the fixpoint body once, by construction).
+    of the fixpoint body once, by construction).  ``plan`` is the push
+    sweeps' destination order (:func:`~repro.core.engine.push_plan`), built
+    once per request where :func:`_hoists_push_plan` says so, else None.
     """
     cbl: Any
     nv: int
     live: jax.Array
     params: Dict[str, Any]
     consts: Dict[str, Any]
+    plan: Any = None
+
+
+class ProgramStats(NamedTuple):
+    """What :func:`run_program` reports with ``return_stats``: the sweeps
+    the fixpoint took and the push plans the executor built (0 or 1)."""
+    sweeps: jax.Array
+    plan_builds: jax.Array
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,15 +256,27 @@ def registered_programs() -> Tuple[str, ...]:
 # Executor
 # ---------------------------------------------------------------------------
 
-def _run_sweep(cbl, sw: Sweep, x, active, impl: str):
+def _hoists_push_plan(cbl, prog: VertexProgram, impl: str) -> bool:
+    """Whether the executor sorts the push sweeps' lanes once per request
+    rather than once per sweep: a single-device store, the XLA engine, and
+    a program with a push sweep."""
+    return (isinstance(cbl, CBList) and impl == "xla"
+            and any(sw.direction == "push" for sw in prog.sweeps))
+
+
+def _run_sweep(ctx: ProgramContext, sw: Sweep, x, active, impl: str):
     if sw.direction == "push_feat":
-        return process_edge_push_feat(cbl, x, active, weighted=sw.weighted,
-                                      impl=impl)
-    entry = process_edge_push if sw.direction == "push" else process_edge_pull
-    if sw.message is None:
-        return entry(cbl, x, active, combine=sw.combine, impl=impl)
-    return entry(cbl, x, active, dense_f=sw.message, combine=sw.combine,
-                 impl=impl)
+        return process_edge_push_feat(ctx.cbl, x, active,
+                                      weighted=sw.weighted, impl=impl)
+    kw = {} if sw.message is None else {"dense_f": sw.message}
+    if sw.direction == "push":
+        if ctx.plan is not None:
+            return _process_edge_push(ctx.cbl, x, active, ctx.plan,
+                                      combine=sw.combine, impl=impl, **kw)
+        return process_edge_push(ctx.cbl, x, active, combine=sw.combine,
+                                 impl=impl, **kw)
+    return process_edge_pull(ctx.cbl, x, active, combine=sw.combine,
+                             impl=impl, **kw)
 
 
 def _step(ctx: ProgramContext, prog: VertexProgram, state, frontier,
@@ -262,7 +286,7 @@ def _step(ctx: ProgramContext, prog: VertexProgram, state, frontier,
     for sw in prog.sweeps:
         x = sw.pre(ctx, new) if sw.pre is not None else new
         act = frontier if (frontier is not None and sw.use_frontier) else None
-        acc = _run_sweep(ctx.cbl, sw, x, act, impl)
+        acc = _run_sweep(ctx, sw, x, act, impl)
         if sw.apply is not None:
             with jax.named_scope("sweep.apply"):
                 new = sw.apply(ctx, new, acc)
@@ -328,7 +352,7 @@ def _retract_unsupported(ctx: ProgramContext, prog: VertexProgram, state,
 
     def body(carry):
         s, it, _ = carry
-        cand = _run_sweep(ctx.cbl, sw, s, None, impl)
+        cand = _run_sweep(ctx, sw, s, None, impl)
         new = jnp.where(anchor_mask, anchor_val,
                         jnp.where(s < cand, INF, s))
         return new, it + jnp.int32(1), (new != s).any()
@@ -350,6 +374,8 @@ def _run_program(cbl, warm, params, *, prog: VertexProgram, impl: str,
     merged = dict(params)
     merged.update(static_kv)
     ctx = ProgramContext(cbl=cbl, nv=nv, live=live, params=merged, consts={})
+    if _hoists_push_plan(cbl, prog, impl):
+        ctx = ctx._replace(plan=push_plan(cbl))
     if prog.setup is not None:
         ctx = ctx._replace(consts=prog.setup(ctx))
     frontier_mode = prog.task == "frontier"
@@ -368,7 +394,7 @@ def _run_program(cbl, warm, params, *, prog: VertexProgram, impl: str,
 
     state, iters = _fixpoint(ctx, prog, state, frontier, max_iters, impl)
     out = prog.finalize(ctx, state) if prog.finalize is not None else state
-    return out, iters
+    return out, ProgramStats(iters, jnp.int32(ctx.plan is not None))
 
 
 def run_program(cbl, prog: VertexProgram, *, warm=None,
@@ -385,8 +411,9 @@ def run_program(cbl, prog: VertexProgram, *, warm=None,
     program's ``task`` metadata.  ``**params`` are forwarded to the program
     hooks through ``ctx.params`` — names in ``prog.static_params`` become
     jit-static, the rest are traced.  With ``return_stats`` the executor
-    also returns the iteration count the fixpoint took; one compiled
-    program serves both forms.
+    also returns a :class:`ProgramStats`: the iteration count the fixpoint
+    took and whether it built a push plan; one compiled program serves both
+    forms.
     """
     if impl is None:
         from repro.core.tuner import choose_engine_impl
@@ -404,6 +431,6 @@ def run_program(cbl, prog: VertexProgram, *, warm=None,
     # jit-honest locality profile: taken here at the host-side entry point,
     # outside the traced sweep (one flag check when obs is off)
     obs.record_sweep(cbl, task=prog.task)
-    out, iters = _run_program(cbl, warm, params, prog=prog, impl=impl,
+    out, stats = _run_program(cbl, warm, params, prog=prog, impl=impl,
                               max_iters=int(max_iters), static_kv=static_kv)
-    return (out, iters) if return_stats else out
+    return (out, stats) if return_stats else out

@@ -627,10 +627,14 @@ class GraphService:
         call_kw = dict(kw)
         if prog.needs_source:
             call_kw["source"] = jnp.int32(source)
-        out, sweeps = run_program(cbl, prog, warm=warm, impl=impl,
-                                  return_stats=True, **call_kw)
+        out, stats = run_program(cbl, prog, warm=warm, impl=impl,
+                                 return_stats=True, **call_kw)
         if obs.enabled():
-            obs.counter("program.sweeps", program=name).inc(int(sweeps))
+            obs.counter("program.sweeps", program=name).inc(
+                int(stats.sweeps))
+            if int(stats.plan_builds):
+                obs.counter("program.plan_builds", program=name).inc(
+                    int(stats.plan_builds))
 
         self._cache[key] = (epoch, self._deletes_applied, dict(kw), out)
         return out

@@ -98,3 +98,21 @@ def test_process_edge_push_pallas_sweep(one_chip):
                         cbl, x)
     text = compiled.as_text()
     assert "block_gather" in text and "gtchain_segment_matmul" in text
+
+
+def test_planned_push_sweep_scale22(one_chip):
+    """One ``impl="xla"`` push sweep over a scale-22 plan: no sort and no
+    scatter, and it fits the chip beside the plan."""
+    from repro.core.engine import SEMIRINGS, _planned_push, push_plan
+    cbl = jax.eval_shape(lambda: cblist.empty(NV, NB, LANES))
+    plan = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                       sharding=one_chip),
+                        jax.eval_shape(push_plan, cbl))
+    x = jax.ShapeDtypeStruct((NV,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda p, v: _planned_push(
+        p, v, None, lambda xs, w: xs, SEMIRINGS["sum"])).lower(
+            plan, x).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

@@ -201,3 +201,16 @@ def test_flush_control_span_holds_the_publish_wait(live_obs):
     (a, b), = spans["serve.flush"]
     assert any(lo <= a and b <= hi for lo, hi in control)
     assert svc.epoch == 1
+
+
+@pytest.mark.parametrize("n_shards,builds", [(1, 1), (2, 0)])
+def test_pagerank_counts_its_plan_builds_beside_its_sweeps(live_obs, n_shards,
+                                                           builds):
+    """A served PageRank on one device sorts its lanes by destination once
+    for all 20 sweeps (``program.plan_builds`` beside ``program.sweeps``);
+    a sharded service sorts in each sweep, and counts no build."""
+    svc = _mk_service(n_shards=n_shards)
+    svc.analytics("pagerank", max_iters=20, tol=0.0)
+    counters = obs.report()["metrics"]["counters"]
+    assert counters["program.sweeps{program=pagerank}"] == 20
+    assert counters.get("program.plan_builds{program=pagerank}", 0) == builds

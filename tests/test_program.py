@@ -3,7 +3,9 @@
 1. Bit-exact equivalence: every built-in workload through ``run_program``
    vs the frozen pre-refactor drivers (repro.graph._legacy), across
    ``impl="xla" | "pallas"`` and ``n_shards = 1 | 2 | 8``, including the
-   incremental warm-start/retraction paths.  The "pallas" cases run the
+   incremental warm-start/retraction paths.  The drivers push through the
+   executor's sweep: a plan per sweep where the executor holds one a
+   request (tests/test_push_plan.py checks the plan's sums).  The "pallas" cases run the
    compiled kernels on a TPU and the interpreted ones elsewhere; the
    MXU kernels only sum, so min-semiring workloads must refuse them.
 2. A custom program (max-reachable-id) registered through
@@ -17,7 +19,8 @@ import numpy as np
 import pytest
 
 from repro.core import batch_update, build_from_coo
-from repro.core.cblist import blocks_needed, to_coo
+from repro.core.cblist import CBList, blocks_needed, to_coo
+from repro.core.engine import _process_edge_push, process_edge_push, push_plan
 from repro.core.program import (Sweep, VertexProgram, get_program,
                                 has_program, run_program)
 from repro.core.tuner import choose_plan
@@ -55,6 +58,28 @@ def graphs():
     cbl2 = batch_update(cbl, jnp.asarray(us), jnp.asarray(ud),
                         jnp.asarray(uw), jnp.asarray(op))
     return cbl, cbl2
+
+
+def _executor_sweep(cbl, x, active=None, *, impl="xla", **kw):
+    """The push sweep as the executor runs it: over a push plan where it
+    builds one (a single-device store, ``impl="xla"``), else the public
+    sweep."""
+    if isinstance(cbl, CBList) and impl == "xla":
+        return _process_edge_push(cbl, x, active, push_plan(cbl), impl=impl,
+                                  **kw)
+    return process_edge_push(cbl, x, active, impl=impl, **kw)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _frozen_loops_on_the_executor_sweep():
+    """The frozen drivers sweep through the executor's push, so that both
+    sides sum each destination's messages in one order; their jitted
+    programs are traced anew on either side of the swap."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(legacy, "process_edge_push", _executor_sweep)
+        jax.clear_caches()
+        yield
+    jax.clear_caches()
 
 
 def _as_shards(cbl, n_shards):
@@ -123,13 +148,13 @@ def test_incremental_equivalence(graphs, n_shards):
 
 def test_run_program_stats_warm_start_saves_iterations(graphs):
     cbl, cbl2 = graphs
-    prev, cold_iters = run_program(cbl2, alg.PAGERANK, damping=0.85, tol=1e-8,
-                                   max_iters=100, return_stats=True)
-    warm, warm_iters = run_program(cbl2, alg.PAGERANK, warm=prev,
+    prev, cold = run_program(cbl2, alg.PAGERANK, damping=0.85, tol=1e-8,
+                             max_iters=100, return_stats=True)
+    warm, warm_stats = run_program(cbl2, alg.PAGERANK, warm=prev,
                                    damping=0.85, tol=1e-8, max_iters=100,
                                    return_stats=True)
-    assert int(warm_iters) <= int(cold_iters)
-    assert int(warm_iters) <= 2            # converged fixpoint re-enters fast
+    assert int(warm_stats.sweeps) <= int(cold.sweeps)
+    assert int(warm_stats.sweeps) <= 2     # converged fixpoint re-enters fast
     np.testing.assert_allclose(np.asarray(prev), np.asarray(warm), atol=1e-6)
 
 
