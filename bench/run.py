@@ -147,7 +147,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     log(f"cell {name} seed {seed} seconds {seconds} trace {int(trace)} "
         f"device {devs[0].platform}/{kind} x{len(devs)} cache {cache}")
 
-    dep = deploy.build(cell.config, cell.params, seed)
+    dep = deploy.build(cell.config, cell.params, seed, devs)
     log(f"graph: scale {cell.config['scale']}: {dep.nv} vertex ids, "
         f"{dep.edges_generated} tuples generated, {len(dep.host_edges[0])} "
         f"arcs stored, each edge both ways, after dropping self-loops and "

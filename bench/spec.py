@@ -9,6 +9,17 @@ file and an entry and edits nothing:
     traffic/<law>.py           a law that mixes name: a loop, a key law
     workloads/<cell>.json      a cell's own parameters (log capacity, plan)
     metrics/<metric>.py        a metric's reader: ``read(ctx) -> float | None``
+    tests/tiny/<config>.json   the configuration's CPU sizes for the tests:
+                               ``scale``, ``vertex_capacity``, ``num_blocks``
+                               and the ``seconds`` a test run measures
+
+A configuration's ``store.shards`` decides how it is built
+(:mod:`bench.deploy`).  With 1 the graph is drawn and loaded on one chip.
+With more, the store is held by the cell's chips together (the cell's
+``chips`` divide ``shards``): each shard's device draws a part of the
+tuples (:func:`bench.graph500.generate_parts`), the host drops repeats and
+cuts the ids into ranges of equal block demand, and each device loads its
+own shards; ``store.num_blocks`` is then the capacity of one shard.
 """
 from __future__ import annotations
 

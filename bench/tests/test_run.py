@@ -5,6 +5,7 @@ import json
 import pytest
 
 from bench import run, spec
+from bench.tests.conftest import run_on_devices, tiny_sizes
 
 
 def test_no_tpu_means_no_result(capsys):
@@ -24,13 +25,25 @@ def _last_line(tiny, monkeypatch, capsys, argv):
     return json.loads(out.out.strip().splitlines()[-1]), out.err
 
 
-@pytest.mark.parametrize("cell,seconds", [("g500-s22.analytics", 0.5),
-                                          ("g500-s20-serve.ingest", 0.5)])
+def _result(tiny, monkeypatch, capsys, chips, argv):
+    """The last line and the standard error of a run; a cell on more than
+    one chip runs in a child process with that many devices."""
+    if chips == 1:
+        return _last_line(tiny, monkeypatch, capsys, argv)
+    proc = run_on_devices(*tiny, chips, argv)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr
+
+
+@pytest.mark.parametrize("cell,seconds", [
+    (w["name"], tiny_sizes(w["config"])["seconds"])
+    for w in spec.benchmark()["workloads"]])
 def test_result_line_schema(tiny, monkeypatch, capsys, cell, seconds):
-    res, err = _last_line(tiny, monkeypatch, capsys,
-                          ["--workload", cell, "--seed", str(2**33 + 5),
-                           "--seconds", str(seconds), "--trace", "0"])
     root, bench = tiny
+    chips = spec.load_cell(cell, root, bench).chips
+    res, err = _result(tiny, monkeypatch, capsys, chips,
+                       ["--workload", cell, "--seed", str(2**33 + 5),
+                        "--seconds", str(seconds), "--trace", "0"])
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
     assert list(res)[-1] == "checks"
@@ -42,6 +55,7 @@ def test_result_line_schema(tiny, monkeypatch, capsys, cell, seconds):
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert set(res["device"]) == {"platform", "kind", "count",
                                   "memory_peak_bytes"}
+    assert res["device"]["count"] == chips
     tail = err.strip().splitlines()[-len(res["checks"]):]
     for line, (name, c) in zip(tail, res["checks"].items()):
         assert line.startswith(f"check {name}: {c['value']} <= {c['limit']}")
